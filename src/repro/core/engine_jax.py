@@ -30,6 +30,7 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402  (after x64 flag)
 
+from repro import device  # noqa: E402
 from repro.core.elimination import Generator, Psi  # noqa: E402
 from repro.core.gfjs import GFJS, LevelSummary, generate_gfjs  # noqa: E402
 from repro.core.potentials import INT, Factor, pack_keys  # noqa: E402
@@ -287,6 +288,7 @@ def desummarize_jax(
                    for v in lvl.vars):
                 # codes past the int32 kernel range (domains >= 2**31
                 # values): numpy-expand this level instead of wrapping
+                device.count_host_fallback("codes_past_int32")
                 for v in lvl.vars:
                     col = np.repeat(lvl.key_cols[v], lvl.freq)
                     out[v] = gfjs.domains[v].decode(col) if decode else col
@@ -315,8 +317,8 @@ def desummarize_jax(
 # the same pass.  The host sees one scalar per psi (the new frontier size,
 # needed to pick the next padding bucket) and the final per-level arrays when
 # a LevelSummary is emitted.  numpy (`generate_gfjs`) remains the
-# dynamic-shape oracle; `generate_gfjs_jax` falls back to it whenever the
-# int32/packing preconditions don't hold.
+# dynamic-shape oracle; `generate_gfjs_jax` falls back to it (and counts the
+# fallback) whenever the int32/packing preconditions don't hold.
 
 
 @dataclass
@@ -343,7 +345,7 @@ def _radix_packable(sizes: Sequence[int]) -> bool:
     return True
 
 
-def _jax_generable(gen: Generator) -> bool:
+def jax_generable(gen: Generator) -> bool:
     """Do the int32-kernel / int64-packing preconditions hold?"""
     if gen.join_size > I32_MAX or len(gen.root_codes) > I32_MAX:
         return False
@@ -498,9 +500,11 @@ def generate_gfjs_jax(
     (expansion is order-preserving in both engines).  The numpy path remains
     authoritative for dynamic shapes, trace recording (incremental
     maintenance needs host (src, cidx) caches), and any generator outside
-    the int32/packing envelope (`_jax_generable`).
+    the int32/packing envelope (`jax_generable`) — counted as a host
+    fallback.
     """
-    if not _jax_generable(gen):
+    if not jax_generable(gen):
+        device.count_host_fallback("not_generable")
         return generate_gfjs(gen, domains)
 
     levels_out: List[LevelSummary] = [

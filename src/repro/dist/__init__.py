@@ -14,7 +14,7 @@ Submodule re-exports resolve lazily (PEP 562): ``sharding`` and
 ``act_sharding`` import jax at module level, and eagerly pulling them here
 would force the jax import onto every consumer of the (numpy-only)
 partition layer — the planner imports ``repro.dist.partition`` and must
-stay jax-free (see ``plan/search.py::_select_backends``).
+stay jax-free (see ``repro/device.py``).
 """
 
 _SHARDING = {"ShardingRules", "DEFAULT_RULES", "SP_FSDP_RULES", "param_specs"}

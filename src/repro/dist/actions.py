@@ -200,10 +200,14 @@ def _worker_init(parent_sys_path: List[str]) -> None:
 
     Adopts the coordinator's ``sys.path`` (spawn children only inherit the
     environment, not in-process path edits) and marks the process as a
-    worker so fault hooks and the registry reset become live.
+    worker so fault hooks and the registry reset become live.  Workers run
+    the numpy engine and are held to the CPU: on a TPU host the
+    coordinator owns the chip, and a worker reaching for it would fail or
+    hang.
     """
     global _IN_WORKER
     _IN_WORKER = True
+    os.environ["JAX_PLATFORMS"] = "cpu"
     for p in parent_sys_path:
         if p not in sys.path:
             sys.path.append(p)

@@ -20,7 +20,7 @@ replicated (DESIGN.md §15 discusses when that trade is worth it).
 The module is importable without jax (the planner consults
 :func:`choose_partition_var`); the device-parallel entry points —
 :func:`partition_histogram`, :func:`sharded_potential_counts` (absorbed
-from the retired ``dist/gj_parallel.py``) — import ``shard_map`` lazily
+from the retired ``dist/gj_parallel.py``) — import jax lazily
 and run one program per mesh-axis device.
 """
 
@@ -281,21 +281,24 @@ def sharded_potential_counts(mesh, axis: str, codes, num_codes: int):
     import functools
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     ndev = mesh.shape[axis]
     n = codes.shape[0]
     n_pad = -(-max(n, 1) // ndev) * ndev
-    padded = jnp.full((n_pad,), num_codes, jnp.int32).at[:n].set(
-        jnp.asarray(codes, jnp.int32))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P())
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(axis),
+                       out_specs=P())
     def _count(local):
         hist = jnp.zeros((num_codes + 1,), jnp.int64).at[local].add(1)
         return jax.lax.psum(hist, axis)
 
-    return _count(padded)[:num_codes]
+    # the mesh context: `jax.make_mesh` axes are Explicit, so the padding
+    # scatter and the shard_map must trace against this mesh
+    with jax.set_mesh(mesh):
+        padded = jnp.full((n_pad,), num_codes, jnp.int32).at[:n].set(
+            jnp.asarray(codes, jnp.int32))
+        return _count(padded)[:num_codes]
 
 
 # ---------------------------------------------------------------------------

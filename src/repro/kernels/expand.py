@@ -41,26 +41,78 @@ OT = 512
 RB = OT
 
 
-def _expand_kernel(start_block, bounds0, bounds1, payload0, payload1, out_ref):
-    """One output tile: recover run indices and gather payload."""
+def _expand_kernel(start_block, bounds0, bounds1, payload0, payload1,
+                   out_ref):
+    """One output tile: recover run indices once, gather K payload rows.
+
+    The window is two [1, RB] bounds blocks and two [K, RB] payload blocks.
+    Each half is searched and picked on its own — Mosaic cannot concatenate
+    vectors at offsets past the first tile — and the run index is the sum
+    of the two halves' counts.  Rows run along sublanes and runs along
+    lanes, so the per-payload results come out as [OT, 1] columns; they
+    are packed into the lanes of one [OT, 128] tile and transposed once
+    into the lane-dense [K, OT] output block.
+    """
     i = pl.program_id(0)
-    # 2-D iotas (TPU Mosaic requires >=2D); rows = output pos, cols = runs
-    t = (jax.lax.broadcasted_iota(jnp.int32, (OT, 2 * RB), 0) + i * OT)
-    j = jax.lax.broadcasted_iota(jnp.int32, (OT, 2 * RB), 1)
-    bounds = jnp.concatenate([bounds0[...], bounds1[...]])     # [2*RB]
-    payload = jnp.concatenate([payload0[...], payload1[...]])  # [2*RB]
-
-    # comparison-matrix run search: idx[k] = #j with bounds[j] <= t[k]
-    cmp = (bounds[None, :] <= t).astype(jnp.int32)             # [OT, 2RB]
-    # pin the accumulator dtypes: x64 mode would promote these sums to int64,
-    # which the int32 output ref rejects
-    idx = jnp.sum(cmp, axis=1, keepdims=True, dtype=jnp.int32)  # [OT, 1]
+    k = payload0.shape[0]
+    t = jax.lax.broadcasted_iota(jnp.int32, (OT, RB), 0) + i * OT
+    j = jax.lax.broadcasted_iota(jnp.int32, (OT, RB), 1)
+    # comparison-matrix run search: idx[t] = #j with bounds[j] <= t.  Pin
+    # the accumulator dtypes: x64 mode would promote these sums to int64
+    idx = (jnp.sum((bounds0[...] <= t).astype(jnp.int32), axis=1,
+                   keepdims=True, dtype=jnp.int32)
+           + jnp.sum((bounds1[...] <= t).astype(jnp.int32), axis=1,
+                     keepdims=True, dtype=jnp.int32))           # [OT, 1]
     idx = jnp.minimum(idx, 2 * RB - 1)
-
+    dt = out_ref.dtype
+    pick0 = (j == idx).astype(dt)                               # [OT, RB]
+    pick1 = (j + RB == idx).astype(dt)
+    p0, p1 = payload0[...], payload1[...]
     # select-and-sum payload pick (exact for any int payload)
-    pick = (j == idx).astype(payload.dtype)                    # [OT, 2RB]
-    out_ref[...] = jnp.sum(pick * payload[None, :], axis=1,
-                           dtype=out_ref.dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (OT, 128), 1)
+    res = jnp.zeros((OT, 128), dt)
+    for q in range(k):
+        col = (jnp.sum(pick0 * p0[q:q + 1, :], axis=1, keepdims=True,
+                       dtype=dt)
+               + jnp.sum(pick1 * p1[q:q + 1, :], axis=1, keepdims=True,
+                         dtype=dt))                             # [OT, 1]
+        res = jnp.where(lane == q, col, res)
+    out_ref[...] = res.T[:k, :]
+
+
+def expand_call(payloads_p: jax.Array, bounds_p: jax.Array,
+                start_block: jax.Array, *, t_pad: int,
+                interpret: bool) -> jax.Array:
+    """The kernel launch: [K, pad_to] payloads -> [K, t_pad] expansion.
+
+    K <= 128 (the payload rows share one [OT, 128] transpose tile).  Index
+    maps return int32 literals: under x64 a bare ``0`` would be an int64
+    constant, which Mosaic cannot legalize.
+    """
+    assert t_pad % OT == 0, "t_pad must be a multiple of the output tile"
+    k, pad_to = payloads_p.shape
+    assert k <= 128, "at most 128 payload rows per launch"
+    assert pad_to == bounds_p.shape[0], "payloads must match bounds padding"
+
+    def row(block):
+        return jnp.int32(0), block
+
+    return pl.pallas_call(
+        _expand_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(t_pad // OT,),
+            in_specs=[
+                pl.BlockSpec((1, RB), lambda i, sb: row(sb[i])),
+                pl.BlockSpec((1, RB), lambda i, sb: row(sb[i] + 1)),
+                pl.BlockSpec((k, RB), lambda i, sb: row(sb[i])),
+                pl.BlockSpec((k, RB), lambda i, sb: row(sb[i] + 1)),
+            ],
+            out_specs=pl.BlockSpec((k, OT), lambda i, sb: row(i)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((k, t_pad), payloads_p.dtype),
+        interpret=interpret,
+    )(start_block, bounds_p[None], bounds_p[None], payloads_p, payloads_p)
 
 
 @functools.partial(jax.jit, static_argnames=("t_pad",))
@@ -91,7 +143,7 @@ def launch_meta(bounds: jax.Array, *, t_pad: int):
 
 @functools.partial(jax.jit, static_argnames=("t_pad", "interpret"))
 def expand_gather_with_meta(
-    payload_p: jax.Array,    # [pad_to] int32 — pre-padded payload
+    payload_p: jax.Array,    # [pad_to] — pre-padded payload
     bounds_p: jax.Array,     # [pad_to] int32 — padded prefix sums
     start_block: jax.Array,  # [t_pad // OT] int32
     *,
@@ -99,25 +151,8 @@ def expand_gather_with_meta(
     interpret: bool = False,
 ) -> jax.Array:
     """Expansion against precomputed `launch_meta` (memoized-level path)."""
-    assert t_pad % OT == 0, "t_pad must be a multiple of the output tile"
-    grid = t_pad // OT
-    out = pl.pallas_call(
-        _expand_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i],)),
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i] + 1,)),
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i],)),
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i] + 1,)),
-            ],
-            out_specs=pl.BlockSpec((OT,), lambda i, sb: (i,)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((t_pad,), payload_p.dtype),
-        interpret=interpret,
-    )(start_block, bounds_p, bounds_p, payload_p, payload_p)
-    return out
+    return expand_call(payload_p[None], bounds_p, start_block, t_pad=t_pad,
+                       interpret=interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("t_pad", "interpret"))

@@ -3,12 +3,13 @@
 Desummarization and frontier expansion both expand *several* payload columns
 by the *same* run-length structure: every variable of a GFJS level shares the
 level's bounds, and a generation step needs (src, CSR start, offsets) plus
-every frontier column expanded by one psi's counts.  The per-column kernel
-(`expand.py`) pays the 2*RB comparison-matrix run search — the dominant VPU
-cost — once per column, plus one kernel launch and one pass over the bounds
-window per column.
+every frontier column expanded by one psi's counts.  Expanding column by
+column (`expand_gather`, a K=1 launch) pays the 2*RB comparison-matrix run
+search — the dominant VPU cost — once per column, plus one kernel launch
+and one pass over the bounds window per column.
 
-This kernel recovers each output tile's run index **once** and then gathers
+The kernel (`expand.py::_expand_kernel`, shared by both entry points)
+recovers each output tile's run index **once** and then gathers
 K payload rows with the same one-hot pick matrix: per output element the
 search costs 2*RB int ops regardless of K, and the per-payload select-and-sum
 is the only K-proportional term.  HBM traffic drops too — the bounds window
@@ -30,31 +31,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.expand import OT, RB, launch_meta
-
-
-def _expand_many_kernel(start_block, bounds0, bounds1, payload0, payload1,
-                        out_ref):
-    """One output tile: recover run indices once, gather K payload rows."""
-    i = pl.program_id(0)
-    k = payload0.shape[0]
-    t = (jax.lax.broadcasted_iota(jnp.int32, (OT, 2 * RB), 0) + i * OT)
-    j = jax.lax.broadcasted_iota(jnp.int32, (OT, 2 * RB), 1)
-    bounds = jnp.concatenate([bounds0[...], bounds1[...]])          # [2*RB]
-    payload = jnp.concatenate([payload0[...], payload1[...]], axis=1)  # [K,2RB]
-
-    # the amortized part: ONE comparison-matrix run search for all K payloads
-    cmp = (bounds[None, :] <= t).astype(jnp.int32)                  # [OT,2RB]
-    idx = jnp.sum(cmp, axis=1, keepdims=True, dtype=jnp.int32)      # [OT,1]
-    idx = jnp.minimum(idx, 2 * RB - 1)
-    pick = (j == idx).astype(payload.dtype)                         # [OT,2RB]
-
-    rows = [jnp.sum(pick * payload[q][None, :], axis=1, dtype=out_ref.dtype)
-            for q in range(k)]
-    out_ref[...] = jnp.stack(rows, axis=0)                          # [K,OT]
+from repro.kernels.expand import expand_call, launch_meta
 
 
 @functools.partial(jax.jit, static_argnames=("t_pad", "interpret"))
@@ -67,27 +45,8 @@ def expand_gather_many_with_meta(
     interpret: bool = False,
 ) -> jax.Array:
     """Fused expansion against precomputed launch metadata ([K, t_pad])."""
-    assert t_pad % OT == 0, "t_pad must be a multiple of the output tile"
-    k, pad_to = payloads.shape
-    assert pad_to == bounds_p.shape[0], "payloads must match bounds padding"
-    grid = t_pad // OT
-    out = pl.pallas_call(
-        _expand_many_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i],)),
-                pl.BlockSpec((RB,), lambda i, sb: (sb[i] + 1,)),
-                pl.BlockSpec((k, RB), lambda i, sb: (0, sb[i])),
-                pl.BlockSpec((k, RB), lambda i, sb: (0, sb[i] + 1)),
-            ],
-            out_specs=pl.BlockSpec((k, OT), lambda i, sb: (0, i)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((k, t_pad), payloads.dtype),
-        interpret=interpret,
-    )(start_block, bounds_p, bounds_p, payloads, payloads)
-    return out
+    return expand_call(payloads, bounds_p, start_block, t_pad=t_pad,
+                       interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("t_pad", "interpret"))

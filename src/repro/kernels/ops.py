@@ -3,7 +3,8 @@
 These are what the JAX GJ engine calls.  Responsibilities:
 
 * interpret-mode dispatch: on CPU backends the kernels execute their Python
-  bodies (`interpret=True`); on TPU they compile to Mosaic.
+  bodies (`interpret=True`); on TPU they compile to Mosaic.  The platform
+  comes from the one probe in `repro.device`.
 * bucketized padding: output sizes are data-dependent in GJ, so callers pass
   the exact total and we round up to the next power-of-two bucket — jit
   caches stay bounded at O(log max-size) entries (DESIGN.md §2).
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import device
 from repro.kernels import boundaries as _boundaries
 from repro.kernels import dense_contract as _dense
 from repro.kernels import expand as _expand
@@ -45,7 +47,8 @@ def _launch(kernel: str, expanded_bytes: int = 0, **args):
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels unless the platform probe found a TPU."""
+    return device.platform() != "tpu"
 
 
 def next_bucket(n: int, floor: int = 512) -> int:

@@ -26,19 +26,25 @@ from jax.experimental.pallas import tpu as pltpu
 T = 512  # entries per tile; [T, T] one-hot fits VMEM (1 MiB f32)
 
 
-def _mul_segsum_kernel(seg_ref, x_ref, y_ref, first_ref, part_ref):
-    """Per-tile partial segment sums, relative to the tile's first id."""
-    seg = seg_ref[...]
-    first = seg[0]
-    rel = seg - first                                        # [T] in [0, T)
-    prod = (x_ref[...] * y_ref[...]).astype(jnp.float32)
+def _mul_segsum_kernel(first_ref, seg_ref, x_ref, y_ref, part_ref):
+    """Per-tile partial segment sums, relative to the tile's first id.
+
+    Blocks are [1, T] rows.  ``first_ref`` is the scalar-prefetched
+    per-tile first segment id (computed by the wrapper, so the kernel has
+    no scalar output).  The one-hot is laid out [slot, entry] and
+    contracted against the product row on its entry axis, which gives the
+    per-slot sums directly as a lane-dense [1, T] row.
+    """
+    rel = seg_ref[...] - first_ref[pl.program_id(0)]         # [1, T] in [0, T)
+    prod = x_ref[...] * y_ref[...]                           # [1, T] f32
     s = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)       # out slot
-    onehot = (s == rel[None, :]).astype(jnp.float32)         # [T, T]
-    # MXU: [T, T] @ [T] — per-slot sums of this tile's products
+    onehot = (s == rel).astype(jnp.float32)                  # [T, T]
+    # MXU: [1, T] x [T, T]^T — HIGHEST keeps integer products below 2**24
+    # exact (the default f32 matmul may round operands to bf16)
     part_ref[...] = jax.lax.dot_general(
-        onehot, prod[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)[:, 0]
-    first_ref[0] = first
+        prod, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
@@ -55,28 +61,26 @@ def mul_segsum(
     n_pad = max(-(-n // T), 1) * T
     # pad with an out-of-range segment id so padding lands in a dead slot
     seg_p = jnp.full((n_pad,), num_segments, jnp.int32).at[:n].set(seg_ids)
-    x_p = jnp.zeros((n_pad,), x.dtype).at[:n].set(x)
-    y_p = jnp.zeros((n_pad,), y.dtype).at[:n].set(y)
+    x_p = jnp.zeros((n_pad,), jnp.float32).at[:n].set(x)
+    y_p = jnp.zeros((n_pad,), jnp.float32).at[:n].set(y)
     grid = n_pad // T
+    first = seg_p[::T]                                       # [grid]
 
-    first, parts = pl.pallas_call(
+    def row(i, first_ref):
+        # int32 literal: under x64 a bare 0 is an int64 Mosaic rejects
+        return jnp.int32(0), i
+
+    parts = pl.pallas_call(
         _mul_segsum_kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((T,), lambda i: (i,)),
-            pl.BlockSpec((T,), lambda i: (i,)),
-            pl.BlockSpec((T,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((T,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((grid,), jnp.int32),
-            jax.ShapeDtypeStruct((grid * T,), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((1, T), row)] * 3,
+            out_specs=pl.BlockSpec((1, T), row),
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
-    )(seg_p, x_p, y_p)
+    )(first, seg_p[None], x_p[None], y_p[None])
 
     # stitch: scatter-add each tile's T relative slots at its first id
     parts = parts.reshape(grid, T)

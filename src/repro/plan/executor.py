@@ -17,6 +17,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import device
 from repro.core.elimination import Generator, build_generator
 from repro.core.gfjs import (GFJS, ShardedGFJS, desummarize,
                              desummarize_range, generate_gfjs,
@@ -30,6 +31,8 @@ from repro.plan.stats import QueryStats
 from repro.relational.encoding import EncodedQuery, encode_query
 from repro.relational.query import JoinQuery
 from repro.relational.table import Catalog
+
+_I32_MAX = (1 << 31) - 1   # the device kernels' row range
 
 
 class Executor:
@@ -297,11 +300,20 @@ class Executor:
             self.build_generator()
         backend = (self.plan.backends.get("summarize", "numpy")
                    if self.plan is not None else "numpy")
+        if backend == "jax":
+            # the span and the fallback counter name the engine that runs
+            from repro.core.engine_jax import jax_generable
+            if self.record_trace:
+                # trace capture needs the host (src, cidx) gather indices
+                # that splice-based incremental refresh replays
+                device.count_host_fallback("record_trace")
+                backend = "numpy"
+            elif not jax_generable(self.generator):
+                device.count_host_fallback("not_generable")
+                backend = "numpy"
         with self._phase("summarize", backend=backend):
             t0 = time.perf_counter()
             if self.record_trace:
-                # trace capture needs the host (src, cidx) gather indices
-                # that splice-based incremental refresh replays — numpy only
                 self.expansion_cache = []
                 gfjs = generate_gfjs(self.generator, self.enc.domains,
                                      self.expansion_cache)
@@ -598,16 +610,22 @@ class Executor:
         """
         backend = (self.plan.backends.get("desummarize", "numpy")
                    if self.plan is not None else "numpy")
+        shards = gfjs.shards if isinstance(gfjs, ShardedGFJS) else [gfjs]
+        if backend == "jax" and any(s.join_size > _I32_MAX for s in shards):
+            # past the int32 kernel range the whole expansion runs on numpy:
+            # the plan's backend is a hint, never a hard capability claim
+            device.count_host_fallback("join_past_int32")
+            backend = "numpy"
         with self._phase("desummarize", backend=backend,
                          rows=gfjs.join_size):
             t0 = time.perf_counter()
-            if backend == "jax" and isinstance(gfjs, ShardedGFJS):
-                parts = [_desummarize_jax(s, decode=decode)
-                         for s in gfjs.shards]
+            if backend == "jax":
+                # one fused `expand_gather_many` launch per level
+                from repro.core.engine_jax import desummarize_jax
+                parts = [desummarize_jax(s, decode=decode) for s in shards]
                 out = {v: np.concatenate([p[v] for p in parts])
+                       if len(parts) > 1 else parts[0][v]
                        for v in gfjs.column_order}
-            elif backend == "jax":
-                out = _desummarize_jax(gfjs, decode=decode)
             else:
                 out = desummarize(gfjs, decode=decode)  # dispatches on shape
             self.timings["desummarize"] = time.perf_counter() - t0
@@ -686,22 +704,3 @@ class Executor:
                             calibration=calibration,
                             calibration_source=calibration_source,
                             cached_steps=cached)
-
-
-_I32_MAX = (1 << 31) - 1
-
-
-def _desummarize_jax(gfjs: GFJS, *, decode: bool = True
-                     ) -> Dict[str, np.ndarray]:
-    """RLE expansion through the fused per-level kernel path.
-
-    Delegates to `engine_jax.desummarize_jax` — one `expand_gather_many`
-    launch per level with memoized launch metadata; levels with codes past
-    the int32 range fall back to numpy inside it.  A join size past the
-    int32 kernel range expands fully on numpy instead of raising: the
-    plan's backend choice is a hint, never a hard capability claim.
-    """
-    if gfjs.join_size > _I32_MAX:
-        return desummarize(gfjs, decode=decode)
-    from repro.core.engine_jax import desummarize_jax
-    return desummarize_jax(gfjs, decode=decode)

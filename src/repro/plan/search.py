@@ -22,6 +22,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro import device
 from repro.core.graph import (QueryGraph, decompose_bags, min_fill_order,
                               structurally_acyclic)
 from repro.plan.cost import CostModel
@@ -90,34 +91,6 @@ def beam_orders(model: CostModel, variables: Sequence[str],
         nxt.sort(key=lambda s: (s[0], s[1]))
         states = nxt[:max(beam_width, 1)]
     return [s[1] for s in states]
-
-
-def _select_backends() -> Dict[str, str]:
-    """Phase -> kernel backend.  TPU gets the Pallas paths, CPU stays numpy.
-
-    Only consults jax if something else already imported it: planning must
-    not pay (or force) the jax import — a process that never loaded jax is
-    running the numpy engine by definition.
-
-    Keys pinned here are the ones the executor actually consults:
-    "desummarize" picks between the numpy expansion and the fused
-    `kernels/expand_fused.py` wrapper; "summarize" picks the generation
-    engine — numpy (the dynamic-shape oracle) or the device-resident
-    `engine_jax.generate_gfjs_jax` frontier.  On CPU both stay numpy: the
-    kernels would only run interpreted there, and numpy's dynamic shapes
-    beat bucket-padded interpret execution (DESIGN.md §14 quantifies when
-    the planner should prefer numpy even on device).
-    """
-    import sys
-    jx = sys.modules.get("jax")
-    on_tpu = False
-    if jx is not None:
-        try:
-            on_tpu = jx.default_backend() == "tpu"
-        except Exception:  # pragma: no cover - partially initialized jax
-            on_tpu = False
-    dev = "jax" if on_tpu else "numpy"
-    return {"summarize": dev, "desummarize": dev}
 
 
 def propose_decomposition(
@@ -355,7 +328,12 @@ def _plan_query_inner(enc: EncodedQuery, t0: float, *,
     # re-checks the exact join_size before materializing, so "inmem" here
     # is a hint, never a commitment to an in-memory blow-up
     est_rows = max((s.message_entries for s in steps), default=0.0)
-    backends = _select_backends()
+    # phase -> engine, from the one platform probe: "jax" (the Pallas
+    # kernels and the device-resident generation frontier) on a TPU, numpy
+    # elsewhere, where the kernels could only run interpreted.  These are
+    # the keys the executor consults.
+    dev = device.engine()
+    backends = {"summarize": dev, "desummarize": dev}
     if generation_backend is not None:
         backends["summarize"] = generation_backend
     if partitions > 1:

@@ -68,6 +68,7 @@ from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import device
 from repro.core.api import GraphicalJoin
 from repro.obs.metrics import REGISTRY
 from repro.obs.trace import span as _span
@@ -131,6 +132,9 @@ class JoinService:
                  message_reuse: bool = True,
                  message_cache: Optional[MessageCache] = None) -> None:
         self.catalog = catalog
+        # the one platform probe (repro/device.py), run here so the engine
+        # every plan of this service pins is fixed before the first query
+        self.engine = device.engine()
         self.cache = cache if cache is not None else SummaryCache(
             byte_budget=byte_budget, spill_dir=spill_dir,
             ttl_seconds=ttl_seconds)

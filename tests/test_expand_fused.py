@@ -182,7 +182,7 @@ def test_generate_gfjs_jax_empty_join():
 
 def test_generate_gfjs_jax_fallback_is_oracle(monkeypatch):
     """Outside the int32/packing envelope the numpy oracle runs unchanged."""
-    monkeypatch.setattr(engine_jax, "_jax_generable", lambda gen: False)
+    monkeypatch.setattr(engine_jax, "jax_generable", lambda gen: False)
     cat, query = _random_instance("triangle", 1)
     gj = GraphicalJoin(cat, query)
     gfjs_np = gj.run()

@@ -259,8 +259,9 @@ def test_sharded_range_and_row_access():
 
 
 def test_partition_layer_imports_without_jax():
-    """Planning a partitioned query must never force the jax import
-    (repro.dist resolves its jax-dependent submodules lazily)."""
+    """Planning a partitioned query on the CPU must never force the jax
+    import (repro.dist resolves its jax-dependent submodules lazily, and
+    the platform probe reads JAX_PLATFORMS=cpu without importing jax)."""
     import subprocess
     import sys as _sys
     code = (
@@ -274,7 +275,7 @@ def test_partition_layer_imports_without_jax():
         "GraphicalJoin(cat, q, partitions=4).run()\n"
         "assert 'jax' not in sys.modules, 'jax import leaked'\n"
         "print('ok')\n")
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     out = subprocess.run([_sys.executable, "-c", code], env=env,
