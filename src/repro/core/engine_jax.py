@@ -192,7 +192,7 @@ def segment_weighted_sum(
             _f32_exact_conclusive(values, weights, n, bound):
         out = ops.mul_segsum(seg_ids, values, weights, num_segments,
                              interpret=interpret)
-        return np.asarray(out).astype(INT)
+        return _segsum_wait(out).astype(INT)
     # exact path: pad entries + segment count to power-of-two buckets;
     # padding rows land in a dead trailing segment that gets sliced off
     acc = jnp.float64 if floaty else jnp.int64
@@ -206,8 +206,15 @@ def segment_weighted_sum(
     w_p[:n] = weights
     out = _segsum_padded(jnp.asarray(seg_p), jnp.asarray(x_p),
                          jnp.asarray(w_p), num_segments=s_pad, acc_dtype=acc)
-    res = np.asarray(out)[:num_segments]
+    res = _segsum_wait(out)[:num_segments]
     return res if floaty else res.astype(INT)
+
+
+def _segsum_wait(out: jax.Array) -> np.ndarray:
+    """Wait for a segment sum and copy it to the host, under a
+    ``segsum:wait`` span."""
+    with _span("segsum:wait", cat="algebra", device=True):
+        return np.asarray(out)
 
 
 def weighted_total(
@@ -254,10 +261,12 @@ def group_runs_device(ranks: np.ndarray) -> Tuple[np.ndarray, np.ndarray,
     r_p = np.full(n_pad, PACK_SENTINEL, np.int64)
     r_p[:n] = ranks
     order, new, seg = _sorted_runs(jnp.asarray(r_p))
-    order = np.asarray(order[:n]).astype(INT)
-    new = np.asarray(new[:n])
+    with _span("sort:wait", cat="algebra", device=True):
+        order = np.asarray(order[:n])
+        new = np.asarray(new[:n])
+        seg = np.asarray(seg[:n])
     starts = np.flatnonzero(new)
-    return order, np.asarray(seg[:n]), starts, int(len(starts))
+    return order.astype(INT), seg, starts, int(len(starts))
 
 
 # ---------------------------------------------------------------------------
@@ -296,9 +305,11 @@ def desummarize_jax(
             meta = ops.gfjs_expand_meta(gfjs, li, t_pad)
             payloads = jnp.stack(
                 [jnp.asarray(lvl.key_cols[v], jnp.int32) for v in lvl.vars])
-            cols = np.asarray(
-                ops.rle_expand_many(payloads, None, total,
-                                    interpret=interpret, meta=meta))
+            expanded = ops.rle_expand_many(payloads, None, total,
+                                           interpret=interpret, meta=meta)
+            with _span("desummarize:d2h", cat="gen", device=True) as csp:
+                cols = np.asarray(expanded)
+                csp.set(bytes=cols.nbytes)
             for k, v in enumerate(lvl.vars):
                 out[v] = gfjs.domains[v].decode(cols[k]) if decode \
                     else cols[k]
@@ -461,7 +472,10 @@ def expand_level_jax(
         counts, bounds, start_g, offs = _frontier_lookup(
             parent_cols, jnp.int32(n), dp.keys_p, dp.start_p, dp.count_p,
             radices=dp.radices)
-        total = int(bounds[-1])          # host sync: one scalar per psi
+        with _span("gfjs:sync", cat="gen", device=True,
+                   child=dp.child) as ssp:
+            total = int(bounds[-1])      # host sync: one scalar per psi
+            ssp.set(total=total)
         if total == 0:
             # dead frontier: keep padded shapes, mark zero live rows — the
             # remaining psis of the level still bind their (empty) children
@@ -488,6 +502,33 @@ def expand_level_jax(
         new_vars.append(dp.child)
         n = total
     return cols, p_bucket, p_bucket * fac_acc, tuple(new_vars), n
+
+
+def _emit_level(depth: int, new_vars: Tuple[str, ...],
+                cols: Dict[str, jax.Array], freq: jax.Array,
+                n: int) -> LevelSummary:
+    """Copy a level's ``n`` live runs to the host as a LevelSummary.
+
+    The wait for the device programs that compute the level (the last
+    psi's weights above all) is a ``gfjs:wait:<depth>`` span of its own,
+    so the ``gfjs:emit:<depth>`` span after it holds only the slice, the
+    device-to-host copy and the widening, with the device bytes copied.
+    """
+    with _span(f"gfjs:wait:{depth}", cat="gen", device=True):
+        jax.block_until_ready([freq] + [cols[v] for v in new_vars])
+    with _span(f"gfjs:emit:{depth}", cat="gen", device=True,
+               runs=n) as sp:
+        nbytes = 0
+        keys = {}
+        for v in new_vars:
+            col = np.asarray(cols[v][:n])
+            nbytes += col.nbytes
+            keys[v] = col.astype(INT)
+        runs = np.asarray(freq[:n])
+        nbytes += runs.nbytes
+        level = LevelSummary(new_vars, keys, runs.astype(INT))
+        sp.set(bytes=nbytes)
+    return level
 
 
 def generate_gfjs_jax(
@@ -535,9 +576,6 @@ def generate_gfjs_jax(
                 cols, p_bucket, level, n, interpret=interpret)
             sp.set(runs=n, vars=",".join(new_vars))
         runs_hist.observe(n)
-        levels_out.append(LevelSummary(
-            new_vars,
-            {v: np.asarray(cols[v][:n]).astype(INT) for v in new_vars},
-            np.asarray(freq[:n]).astype(INT)))
+        levels_out.append(_emit_level(depth, new_vars, cols, freq, n))
 
     return GFJS(levels_out, list(gen.column_order), gen.join_size, domains)

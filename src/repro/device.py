@@ -24,6 +24,10 @@ fixed path inside the checkout (the path is part of each cache key, so a
 moving directory never hits).  This is the only place the repository sets
 it.
 
+The TPU probe also registers :func:`on_compile` with ``jax.monitoring``,
+so that each backend compile made while a tracer is active is recorded as
+a ``jax:compile`` span.
+
 Host fallbacks — a phase planned for the device that ran on the host — are
 counted in the process registry under ``engine.host_fallback.<reason>``
 (:func:`count_host_fallback`, read back by :func:`host_fallbacks`).
@@ -37,10 +41,13 @@ from pathlib import Path
 from typing import Dict
 
 from repro.obs.metrics import REGISTRY
+from repro.obs.trace import ambient_tracer
 
 #: the compile cache when JAX_COMPILATION_CACHE_DIR is unset
 CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 HOST_FALLBACK = "engine.host_fallback"
+#: the jax.monitoring event that reports each backend compile's duration
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @functools.cache
@@ -54,6 +61,7 @@ def platform() -> str:
     name = jax.default_backend()
     if name == "tpu":
         _use_compilation_cache(jax)
+        _listen_for_compiles(jax)
     elif named:
         raise RuntimeError(
             f"JAX_PLATFORMS={os.environ['JAX_PLATFORMS']!r} asks for a TPU "
@@ -77,6 +85,24 @@ def _use_compilation_cache(jax) -> None:
         "jax_compilation_cache_dir",
         os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+@functools.cache
+def _listen_for_compiles(jax) -> None:
+    """Hand every backend compile to :func:`on_compile` (once a process)."""
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+
+
+def on_compile(event: str, duration: float, **kw) -> None:
+    """A finished backend compile: under an active tracer, a ``jax:compile``
+    span over the ``duration`` seconds that just ended, so that device-idle
+    time spent compiling is charged to it."""
+    if event != COMPILE_EVENT:
+        return
+    tracer = ambient_tracer()
+    if tracer is not None:
+        now = tracer.clock()
+        tracer.add("jax:compile", now - duration, now, cat="jax")
 
 
 def count_host_fallback(reason: str) -> None:

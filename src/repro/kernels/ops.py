@@ -28,21 +28,15 @@ from repro.kernels import dense_contract as _dense
 from repro.kernels import expand as _expand
 from repro.kernels import expand_fused as _expand_fused
 from repro.kernels import segsum as _segsum
-from repro.obs.metrics import REGISTRY
 from repro.obs.trace import span as _span
 
 F32_EXACT = 1 << 24
 
 
-def _launch(kernel: str, expanded_bytes: int = 0, **args):
-    """Count a kernel launch (+ bytes written by expansions) and open a
-    device-annotated span — `jax.profiler.TraceAnnotation` rides along so
-    host spans line up with device traces.  The span is the ambient no-op
-    when tracing is off; the counters always accumulate."""
-    REGISTRY.counter("kernels.launches").inc()
-    if expanded_bytes:
-        REGISTRY.counter("kernels.bytes_expanded", unit="B").inc(
-            expanded_bytes)
+def _launch(kernel: str, **args):
+    """Open a device-annotated ``kernel:<name>`` span around a launch —
+    `jax.profiler.TraceAnnotation` rides along so host spans line up with
+    device traces.  The ambient no-op when tracing is off."""
     return _span(f"kernel:{kernel}", cat="kernel", device=True, **args)
 
 

@@ -179,7 +179,7 @@ class MetricsRegistry:
 
         The cross-process half of observability: shard workers snapshot
         their (freshly reset) registry and the coordinator merges every
-        reply, so ``kernels.*`` / ``gfjs.*`` numbers look the same whether
+        reply, so ``gfjs.*`` / ``engine.*`` numbers look the same whether
         shards ran on threads or processes.  Counters add, gauges take the
         incoming value (last writer wins, same as ``set``), histograms
         merge bucket-wise.

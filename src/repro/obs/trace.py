@@ -164,14 +164,29 @@ class Tracer:
         across a thread boundary (shard pools), or ``None`` to force a
         root span.
         """
+        sp = Span(name=name, cat=cat, span_id=next(_IDS),
+                  parent_id=self._parent_id(parent), tid=0, args=dict(args))
+        return _SpanCtx(self, sp, device)
+
+    def add(self, name: str, t0: float, t1: float, *, cat: str = "op",
+            parent: Any = _AMBIENT, **args: Any) -> Span:
+        """Record a span that has already ended, ``[t0, t1]`` on this
+        tracer's clock: work reported after the fact, such as a compile
+        JAX announces with its duration once it is done."""
+        sp = Span(name=name, cat=cat, span_id=next(_IDS),
+                  parent_id=self._parent_id(parent),
+                  tid=threading.get_ident(), t0=t0, t1=t1, args=dict(args))
+        self._record(sp)
+        return sp
+
+    def _parent_id(self, parent: Any) -> Optional[int]:
+        """The id of ``parent``, or of this tracer's ambient span in the
+        current context when ``parent`` is left at its default."""
         if parent is _AMBIENT:
             state = _STATE.get()
             parent = state[1] if state is not None and state[0] is self \
                 else None
-        pid = parent.span_id if isinstance(parent, Span) else None
-        sp = Span(name=name, cat=cat, span_id=next(_IDS), parent_id=pid,
-                  tid=0, args=dict(args))
-        return _SpanCtx(self, sp, device)
+        return parent.span_id if isinstance(parent, Span) else None
 
     def _record(self, span: Span) -> None:
         with self._lock:

@@ -332,7 +332,8 @@ class JoinServer:
         with self._span("server:request", kind="frame",
                         query=query.name) as sp:
             if plan is None:
-                plan = self.service.compile(query)
+                with self._span("server:plan", device=True):
+                    plan = self.service.compile(query)
             key = self._key(query, plan)
 
             def build(fl: _Flight) -> ServiceReply:
@@ -411,11 +412,15 @@ class JoinServer:
                 f"ceiling {self.cost_ceiling:.3g} "
                 f"(plan {plan.query_name!r}, {plan.partitions} partition(s))")
         self._gauge("queue_depth", +1)
+        t_wait = time.perf_counter()
         try:
-            ok = self._build_slots.acquire(
-                timeout=self._remaining(deadline, t0))
+            with self._span("server:admit", device=True):
+                ok = self._build_slots.acquire(
+                    timeout=self._remaining(deadline, t0))
         finally:
             self._gauge("queue_depth", -1)
+            REGISTRY.histogram("server.admit_wait_seconds", unit="s").observe(
+                time.perf_counter() - t_wait)
         if not ok:
             self._count("deadline_expired")
             e = DeadlineExceeded(
@@ -448,7 +453,8 @@ class JoinServer:
                 self._count("requests")
                 return np.zeros((0, len(agg_names)), np.float32)
             if plan is None:
-                plan = self.service.compile(query)
+                with self._span("server:plan", device=True):
+                    plan = self.service.compile(query)
             bkey = (self._key(query, plan), key_var, _aggs_signature(aggs))
             b = self._batcher(bkey)
             slot = _Slot(keys)

@@ -23,6 +23,7 @@ path), which is the jit-backed hot loop of the whole subsystem.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
@@ -30,6 +31,7 @@ import numpy as np
 
 from repro.core.gfjs import GFJS
 from repro.core.potentials import INT, _rank_rows, group_ranks
+from repro.obs.trace import span as _span
 
 Predicate = Union[Callable[[np.ndarray], np.ndarray], int, float, str,
                   Sequence, set, frozenset]
@@ -46,6 +48,17 @@ def _run_values(gfjs: GFJS, var: str, codes: np.ndarray) -> np.ndarray:
         raise TypeError(f"variable {var!r} has non-numeric domain "
                         f"({vals.dtype}); only count/distinct apply")
     return vals
+
+
+def _traced(fn):
+    """Run a public frame call under an ``algebra:<name>`` span."""
+    name = f"algebra:{fn.__name__}"
+
+    @functools.wraps(fn)
+    def call(frame, *args, **kw):
+        with _span(name, cat="algebra", device=True):
+            return fn(frame, *args, **kw)
+    return call
 
 
 def _eval_predicate(pred: Predicate, values: np.ndarray) -> np.ndarray:
@@ -127,6 +140,7 @@ class SummaryFrame:
         return float(max(abs(float(vals[0])), abs(float(vals[-1]))))
 
     # -- filtering ---------------------------------------------------------
+    @_traced
     def filter(self, preds: Optional[Mapping[str, Predicate]] = None,
                **kw: Predicate) -> "SummaryFrame":
         """Predicate pushdown: zero failing runs, re-propagate weights.
@@ -169,6 +183,7 @@ class SummaryFrame:
         return SummaryFrame(self.gfjs, new)
 
     # -- scalar aggregates -------------------------------------------------
+    @_traced
     def count(self) -> int:
         """|Q| under the current filters — one O(runs) reduction.
 
@@ -183,6 +198,7 @@ class SummaryFrame:
             self._count = c
         return c
 
+    @_traced
     def sum(self, var: str):
         """SUM(var) over the (filtered) join multiset."""
         from repro.core.engine_jax import weighted_total
@@ -193,13 +209,16 @@ class SummaryFrame:
         out = weighted_total(vals, self.weights[lv], bound=bound)
         return float(out) if vals.dtype.kind == "f" else int(out)
 
+    @_traced
     def mean(self, var: str) -> Optional[float]:
         c = self.count()
         return None if c == 0 else self.sum(var) / c
 
+    @_traced
     def min(self, var: str):
         return self._extreme(var, np.min)
 
+    @_traced
     def max(self, var: str):
         return self._extreme(var, np.max)
 
@@ -213,6 +232,7 @@ class SummaryFrame:
         code = reduce_fn(codes[live])
         return self.gfjs.domains[var].decode(np.asarray([code]))[0]
 
+    @_traced
     def distinct(self, var: str) -> np.ndarray:
         """Sorted distinct raw values of ``var`` with surviving weight."""
         lv = self.level_of(var)
@@ -220,12 +240,14 @@ class SummaryFrame:
         live = np.unique(codes[self.weights[lv] > 0])
         return self.gfjs.domains[var].decode(live)
 
+    @_traced
     def count_distinct(self, var: str) -> int:
         lv = self.level_of(var)
         codes = self.gfjs.levels[lv].key_cols[var]
         return int(len(np.unique(codes[self.weights[lv] > 0])))
 
     # -- grouped aggregates ------------------------------------------------
+    @_traced
     def group_by(self, keys: Union[str, Sequence[str]],
                  **aggs: AggSpec) -> Dict[str, np.ndarray]:
         """GROUP BY ``keys`` with named aggregates, all in O(runs log runs).
@@ -400,12 +422,14 @@ class ShardedSummaryFrame:
         return self.frames[0].level_of(var)   # identical structure per shard
 
     # -- filtering ---------------------------------------------------------
+    @_traced
     def filter(self, preds: Optional[Mapping[str, Predicate]] = None,
                **kw: Predicate) -> "ShardedSummaryFrame":
         return ShardedSummaryFrame(
             self.sharded, [f.filter(preds, **kw) for f in self.frames])
 
     # -- scalar aggregates -------------------------------------------------
+    @_traced
     def count(self) -> int:
         c = getattr(self, "_count", None)
         if c is None:
@@ -413,29 +437,36 @@ class ShardedSummaryFrame:
             self._count = c
         return c
 
+    @_traced
     def sum(self, var: str):
         return sum(f.sum(var) for f in self.frames)
 
+    @_traced
     def mean(self, var: str) -> Optional[float]:
         c = self.count()
         return None if c == 0 else self.sum(var) / c
 
+    @_traced
     def min(self, var: str):
         vals = [v for v in (f.min(var) for f in self.frames) if v is not None]
         return min(vals) if vals else None
 
+    @_traced
     def max(self, var: str):
         vals = [v for v in (f.max(var) for f in self.frames) if v is not None]
         return max(vals) if vals else None
 
+    @_traced
     def distinct(self, var: str) -> np.ndarray:
         return np.unique(np.concatenate(
             [f.distinct(var) for f in self.frames]))
 
+    @_traced
     def count_distinct(self, var: str) -> int:
         return int(len(self.distinct(var)))
 
     # -- grouped aggregates ------------------------------------------------
+    @_traced
     def group_by(self, keys: Union[str, Sequence[str]],
                  **aggs: AggSpec) -> Dict[str, np.ndarray]:
         """GROUP BY with shard merge; same contract as the monolithic frame."""

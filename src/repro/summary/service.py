@@ -112,6 +112,14 @@ class ServiceReply:
         return "\n".join(lines)
 
 
+def _wrap(gfjs, source: str) -> SummaryFrame:
+    """``SummaryFrame.of`` under a ``service:wrap`` span; the frame copies
+    one int64 weight per run of the summary."""
+    with _span("service:wrap", cat="service", device=True, source=source,
+               bytes=8 * gfjs.num_runs()):
+        return SummaryFrame.of(gfjs)
+
+
 class JoinService:
     """Answer join queries from cached summaries; compute-and-reuse on miss."""
 
@@ -345,7 +353,7 @@ class JoinService:
         cached, source = self.cache.get_with_source(key)
         lookup = time.perf_counter() - t0
         if cached is not None:
-            return ServiceReply(SummaryFrame.of(cached), source, key,
+            return ServiceReply(_wrap(cached, source), source, key,
                                 {"cache_lookup": lookup}, plan)
         # a miss after an append: catch the retained state up through the
         # delta chain instead of recomputing from scratch
@@ -373,7 +381,7 @@ class JoinService:
             self._remember_state(query, plan, gj, gfjs, built, key)
         timings = dict(gj.timings)
         timings["cache_lookup"] = lookup
-        return ServiceReply(SummaryFrame.of(gfjs), "computed", key,
+        return ServiceReply(_wrap(gfjs, "computed"), "computed", key,
                             timings, plan)
 
     # -- incremental maintenance ------------------------------------------
@@ -552,7 +560,7 @@ class JoinService:
         timings = {"cache_lookup": lookup, "refresh": dt}
         timings.update({f"refresh_{k}": v for k, v in report.items()
                         if k != "seconds"})
-        return ServiceReply(SummaryFrame.of(new_state.gfjs), "refreshed",
+        return ServiceReply(_wrap(new_state.gfjs, "refreshed"), "refreshed",
                             new_key, timings, plan)
 
     def invalidate(self, table: str) -> int:

@@ -62,7 +62,8 @@ def steered_tpu(monkeypatch):
     """Make the probe see a TPU: JAX itself reports "tpu" in this test.
 
     The probe's cache and JAX's compilation-cache settings are restored
-    afterwards, so no later test sees the steered answer."""
+    afterwards, and its compile listener is kept out of JAX's registry, so
+    no later test sees the steered answer or hears its compiles."""
     jax = pytest.importorskip("jax")
     from jax.experimental.compilation_cache import compilation_cache
     saved = {k: getattr(jax.config, k) for k in (
@@ -71,9 +72,14 @@ def steered_tpu(monkeypatch):
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        lambda fn: None)
     device.platform.cache_clear()
+    device._listen_for_compiles.cache_clear()
     yield jax
     device.platform.cache_clear()
+    device._listen_for_compiles.cache_clear()
     for k, v in saved.items():
         jax.config.update(k, v)
     compilation_cache.reset_cache()
@@ -100,6 +106,29 @@ def test_probe_defers_to_the_cache_dir_environment(steered_tpu, monkeypatch,
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert device.platform() == "tpu"
     assert steered_tpu.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_probe_on_a_tpu_listens_for_compiles(steered_tpu, monkeypatch):
+    heard = []
+    monkeypatch.setattr(steered_tpu.monitoring,
+                        "register_event_duration_secs_listener", heard.append)
+    assert device.platform() == "tpu"
+    assert heard == [device.on_compile]
+
+
+def test_compile_listener_records_a_span_and_counts(monkeypatch):
+    # one span per compile event, none for other events or with no tracer;
+    # clock reads: the tracer's epoch, the span's start, the listener's
+    # "now", the span's end
+    tracer = Tracer(clock=iter([0.0, 0.0, 10.0, 11.0]).__next__)
+    with tracer.span("request") as root:
+        device.on_compile(device.COMPILE_EVENT, 0.25)
+        device.on_compile("/jax/core/compile/jaxpr_trace_duration", 9.0)
+    (sp,) = tracer.find("jax:compile")
+    assert (sp.t0, sp.t1, sp.cat) == (9.75, 10.0, "jax")
+    assert sp.parent_id == root.span_id
+    device.on_compile(device.COMPILE_EVENT, 0.5)    # no tracer: nothing
+    assert len(tracer.find("jax:compile")) == 1
 
 
 def test_probe_refuses_a_missing_tpu(monkeypatch):
