@@ -72,12 +72,26 @@ def _eval_predicate(pred: Predicate, values: np.ndarray) -> np.ndarray:
     return values == pred
 
 
+def _shared(freq: np.ndarray) -> np.ndarray:
+    """Read-only int64 view of a level's run lengths.
+
+    Copies only a level that is not int64 already (e.g. loaded from an
+    older file).  Read-only, so a writer fails loudly instead of corrupting
+    the cached summary that every later frame reads.
+    """
+    w = np.asarray(freq, dtype=INT).view()
+    w.flags.writeable = False
+    return w
+
+
 @dataclass
 class SummaryFrame:
     """A GFJS plus per-level effective run weights (filters applied)."""
 
     gfjs: GFJS
-    weights: List[np.ndarray]  # one int64 array per level, same runs as gfjs
+    # one int64 array per level, same runs as gfjs; an unfiltered frame's
+    # are read-only views of the run lengths, a filtered frame's its own
+    weights: List[np.ndarray]
 
     # -- constructors ------------------------------------------------------
     @staticmethod
@@ -90,7 +104,7 @@ class SummaryFrame:
         from repro.core.gfjs import ShardedGFJS
         if isinstance(gfjs, ShardedGFJS):
             return ShardedSummaryFrame.of(gfjs)
-        return SummaryFrame(gfjs, [lvl.freq.astype(INT) for lvl in gfjs.levels])
+        return SummaryFrame(gfjs, [_shared(lvl.freq) for lvl in gfjs.levels])
 
     # -- structure helpers -------------------------------------------------
     def level_of(self, var: str) -> int:

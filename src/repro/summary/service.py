@@ -113,11 +113,25 @@ class ServiceReply:
 
 
 def _wrap(gfjs, source: str) -> SummaryFrame:
-    """``SummaryFrame.of`` under a ``service:wrap`` span; the frame copies
-    one int64 weight per run of the summary."""
+    """``SummaryFrame.of`` under a ``service:wrap`` span.
+
+    The frame's weights are read-only views of the summary's int64 run
+    lengths (``bytes``: 8 per run); ``copied`` is what the frame allocated,
+    8 per run of each level that had to be widened to int64.
+    """
     with _span("service:wrap", cat="service", device=True, source=source,
-               bytes=8 * gfjs.num_runs()):
-        return SummaryFrame.of(gfjs)
+               bytes=8 * gfjs.num_runs()) as sp:
+        frame = SummaryFrame.of(gfjs)
+        sp.set(copied=_copied_bytes(frame))
+        return frame
+
+
+def _copied_bytes(frame) -> int:
+    """Bytes of ``frame``'s weights that do not alias its run lengths."""
+    frames = getattr(frame, "frames", [frame])
+    return sum(w.nbytes for f in frames
+               for w, lvl in zip(f.weights, f.gfjs.levels)
+               if not np.may_share_memory(w, lvl.freq))
 
 
 class JoinService:

@@ -97,8 +97,10 @@ def test_cache_hit_frame_records_plan_and_wrap():
     assert _parent(tracer, plan).name == "server:request"
     (wrap,) = tracer.find("service:wrap")
     assert _parent(tracer, wrap).name == "service:frame"
+    # the hit's weights alias the cached run lengths: nothing copied
     assert wrap.args == {"source": "memory",
-                         "bytes": 8 * reply.frame.gfjs.num_runs()}
+                         "bytes": 8 * reply.frame.gfjs.num_runs(),
+                         "copied": 0}
 
 
 @pytest.fixture

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core.api import GraphicalJoin
-from repro.core.gfjs import desummarize
+from repro.core.gfjs import GFJS, LevelSummary, desummarize
 from repro.core.oracle import oracle_join
 from repro.relational.query import JoinQuery
 from repro.relational.synth import figure1, lastfm_like
@@ -254,6 +254,65 @@ def test_weights_stay_level_consistent_after_filter():
     # every level's weights must sum to the same filtered count
     totals = {int(w.sum()) for w in frame.weights}
     assert totals == {frame.count()}
+
+
+def _lastfm_summary(kind: str):
+    cat, qs = lastfm_like(n_users=50, n_artists=40, artists_per_user=4,
+                          friends_per_user=3)
+    parts = 3 if kind == "sharded" else 1
+    return GraphicalJoin(cat, qs["lastfm_A1"], partitions=parts).run()
+
+
+def _shard_frames(frame):
+    """(frame, summary) per shard; a monolithic frame is its own shard."""
+    return [(f, f.gfjs) for f in getattr(frame, "frames", [frame])]
+
+
+KINDS = ["monolithic", "sharded"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_unfiltered_weights_share_run_lengths(kind):
+    frame = SummaryFrame.of(_lastfm_summary(kind))
+    for f, g in _shard_frames(frame):
+        for w, lvl in zip(f.weights, g.levels):
+            assert w.dtype == np.int64
+            assert np.shares_memory(w, lvl.freq)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_unfiltered_weights_are_read_only(kind):
+    frame = SummaryFrame.of(_lastfm_summary(kind))
+    for f, _ in _shard_frames(frame):
+        for w in f.weights:
+            with pytest.raises(ValueError):
+                w[...] = 0
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_filter_leaves_run_lengths_unchanged(kind):
+    g = _lastfm_summary(kind)
+    shards = getattr(g, "shards", [g])
+    before = [lvl.freq.copy() for s in shards for lvl in s.levels]
+    filtered = SummaryFrame.of(g).filter(U2=lambda u: u % 3 == 0)
+    assert filtered.count() < g.join_size
+    after = [lvl.freq for s in shards for lvl in s.levels]
+    assert all(np.array_equal(a, b) for a, b in zip(before, after))
+    assert SummaryFrame.of(g).count() == g.join_size
+
+
+def test_narrow_run_lengths_are_widened_to_a_copy():
+    g = _lastfm_summary("monolithic")
+    narrow = GFJS([LevelSummary(lvl.vars, lvl.key_cols,
+                                lvl.freq.astype(np.int32))
+                   for lvl in g.levels],
+                  list(g.column_order), g.join_size, g.domains)
+    frame = SummaryFrame.of(narrow)
+    for w, lvl in zip(frame.weights, narrow.levels):
+        assert w.dtype == np.int64
+        assert not np.shares_memory(w, lvl.freq)
+        assert np.array_equal(w, lvl.freq)
+    assert frame.count() == g.join_size
 
 
 def test_string_domains_reject_numeric_aggregates():

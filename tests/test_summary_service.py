@@ -103,6 +103,38 @@ def test_service_aggregates_match_summary_frame(lastfm):
     assert np.array_equal(got["total"], want["total"])
 
 
+def test_cache_hits_share_the_cached_run_lengths(lastfm):
+    cat, qs = lastfm
+    q = qs["lastfm_A1"]
+    svc = JoinService(cat)
+    svc.frame(q)
+    a, b = svc.frame(q), svc.frame(q)
+    assert a.source == b.source == "memory"
+    assert a.frame.gfjs is b.frame.gfjs
+    for wa, wb, lvl in zip(a.frame.weights, b.frame.weights,
+                           a.frame.gfjs.levels):
+        assert np.shares_memory(wa, wb)
+        assert np.shares_memory(wa, lvl.freq)
+
+
+def test_frame_before_append_keeps_its_total():
+    cat, qs = lastfm_like(n_users=40, n_artists=30, artists_per_user=4,
+                          friends_per_user=3)
+    q = qs["lastfm_A1"]
+    svc = JoinService(cat, incremental=True)
+    old = svc.frame(q).frame
+    old_total = old.gfjs.join_size
+    rng = np.random.default_rng(7)
+    svc.append("user_friends", {"userID": rng.integers(0, 40, 5),
+                                "friendID": rng.integers(0, 40, 5)})
+    reply = svc.frame(q)
+    assert reply.source == "refreshed"
+    assert reply.frame.count() > old_total
+    # the old frame's first count and every level still read the old summary
+    assert old.count() == old_total
+    assert {int(w.sum()) for w in old.weights} == {old_total}
+
+
 def test_lru_order_and_budget():
     rng = np.random.default_rng(0)
     cat = Catalog.of(
