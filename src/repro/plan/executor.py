@@ -216,21 +216,19 @@ class Executor:
         t0 = time.perf_counter()
         if self.plan is not None:
             # pre-compiled plan: every choice is already pinned, so skip
-            # the statistics pass (degree-vector bincounts) and the search
-            # entirely — build only the potentials the generator needs and
-            # hand them to the shared logical-plan constructor.  Under a
-            # partitioned plan even those are skipped: each shard derives
-            # its own potentials from the shard slice, so monolithic
-            # factors would be built and never read.
-            from repro.core.potentials import Factor
+            # the degree-vector bincounts and the search entirely — build
+            # only the potentials the generator needs and hand them to the
+            # shared logical-plan constructor.  Under a partitioned plan
+            # even those are skipped: each shard derives its own
+            # potentials from the shard slice, so monolithic factors would
+            # be built and never read.
             from repro.plan.search import build_logical_plan
-            sizes = self.enc.domain_sizes()
-            factors = [] if self.plan.partitions > 1 else \
-                [Factor.from_columns(cols, sizes)
-                 for cols in self.enc.encoded_tables]
+            stats = (QueryStats(self.enc.domain_sizes(), [], [])
+                     if self.plan.partitions > 1
+                     else QueryStats.of(self.enc, degrees=False))
             self.logical = build_logical_plan(
                 self.enc, early_projection=self.plan.early_projection,
-                stats=QueryStats(sizes, factors, []))
+                stats=stats)
         else:
             self.logical, self.plan = plan_query(
                 self.enc,

@@ -22,11 +22,12 @@ of the only option.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, FrozenSet, List, Optional, Sequence, Tuple
+from typing import Dict, FrozenSet, List, Tuple
 
 import numpy as np
 
 from repro.core.potentials import Factor
+from repro.obs.trace import span as _span
 from repro.relational.encoding import EncodedQuery
 
 DEGREE_CAP = 1 << 22  # max domain size for which we keep a degree vector
@@ -77,12 +78,24 @@ class QueryStats:
     factor_stats: List[FactorStats]
 
     @staticmethod
-    def of(enc: EncodedQuery,
-           factors: Optional[Sequence[Factor]] = None) -> "QueryStats":
+    def of(enc: EncodedQuery, *, degrees: bool = True) -> "QueryStats":
+        """The statistics pass: the potentials (one ``Factor.from_columns``
+        per table occurrence) and, with ``degrees``, each one's
+        :class:`FactorStats` for the cost model.
+
+        A pinned plan costs nothing, so it asks for the potentials alone
+        (``degrees=False``).  The pass runs in a ``plan:stats`` span (args
+        ``rows``, the base rows read, and ``entries``, the potentials'
+        entries).
+        """
         sizes = enc.domain_sizes()
-        if factors is None:
+        with _span("plan:stats", cat="plan", device=True) as sp:
             factors = [Factor.from_columns(cols, sizes)
                        for cols in enc.encoded_tables]
-        fstats = [FactorStats.of(f, sizes, frozenset({qt.table}))
-                  for f, qt in zip(factors, enc.query.tables)]
-        return QueryStats(sizes, list(factors), fstats)
+            fstats = [FactorStats.of(f, sizes, frozenset({qt.table}))
+                      for f, qt in zip(factors, enc.query.tables)] \
+                if degrees else []
+            sp.set(rows=sum(len(next(iter(cols.values()), ()))
+                            for cols in enc.encoded_tables),
+                   entries=sum(f.num_entries for f in factors))
+        return QueryStats(sizes, factors, fstats)

@@ -169,13 +169,16 @@ class SummaryFrame:
             return self
         deep = self._deepest
         nd = self.gfjs.levels[deep].num_runs
-        keep = np.ones(nd, dtype=bool)
-        for var, pred in merged.items():
-            own = self.level_of(var)
-            codes = self.gfjs.levels[own].key_cols[var]
-            mask = _eval_predicate(pred, self.gfjs.domains[var].decode(codes))
-            keep &= mask if own == deep else mask[self._ancestors(deep, own)]
-        deep_w = np.where(keep, self.weights[deep], 0).astype(INT)
+        with _span("filter:mask", cat="algebra", device=True, runs=nd):
+            keep = np.ones(nd, dtype=bool)
+            for var, pred in merged.items():
+                own = self.level_of(var)
+                codes = self.gfjs.levels[own].key_cols[var]
+                mask = _eval_predicate(pred,
+                                       self.gfjs.domains[var].decode(codes))
+                keep &= mask if own == deep \
+                    else mask[self._ancestors(deep, own)]
+            deep_w = np.where(keep, self.weights[deep], 0).astype(INT)
         return self._with_deep_weights(deep_w)
 
     def _with_deep_weights(self, deep_w: np.ndarray) -> "SummaryFrame":
@@ -189,11 +192,13 @@ class SummaryFrame:
         # count bounds every propagated segment sum — the O(1) kernel guard
         bound = float(self.count())
         for j in range(deep):
-            anc = self._ancestors(deep, j)
-            # anc is sorted ascending and dense over 0..runs_j-1
-            new[j] = segment_weighted_sum(
-                anc.astype(np.int32), deep_w, ones,
-                self.gfjs.levels[j].num_runs, bound=bound)
+            runs = self.gfjs.levels[j].num_runs
+            with _span("filter:propagate", cat="algebra", device=True,
+                       level=j, runs=runs):
+                anc = self._ancestors(deep, j)
+                # anc is sorted ascending and dense over 0..runs_j-1
+                new[j] = segment_weighted_sum(
+                    anc.astype(np.int32), deep_w, ones, runs, bound=bound)
         return SummaryFrame(self.gfjs, new)
 
     # -- scalar aggregates -------------------------------------------------

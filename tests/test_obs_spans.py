@@ -4,8 +4,10 @@ Under an active tracer: device generation records one ``gfjs:sync`` per
 psi, and per emitted level a ``gfjs:wait:<d>`` on its device programs and
 a ``gfjs:emit:<d>`` with the bytes it copied; the cache-hit frame records ``server:plan`` and ``service:wrap``;
 every public frame call records an ``algebra:<op>`` span, with the
-device waits of segment sums and grouped-run sorts beneath it; device
-desummarize and the admission queue record their copy and their wait.
+device waits of segment sums and grouped-run sorts beneath it, and a
+filter its mask and each level's propagation; the plan phase's pass over
+the base rows records ``plan:stats``; device desummarize and the admission
+queue record their copy and their wait.
 """
 
 import numpy as np
@@ -175,3 +177,59 @@ def test_admission_queue_records_its_wait():
     assert _parent(tracer, admit).name == "server:request"
     assert waits.count == n0 + 1
 
+
+
+def test_statistics_pass_records_its_rows_on_both_plan_branches():
+    cat, q = _lastfm()
+    base = sum(cat[qt.table].num_rows for qt in q.tables)
+    tracer = Tracer()
+    with tracer.span("searched"):
+        searched = GraphicalJoin(cat, q)
+        plan = searched.plan()
+    with tracer.span("pinned"):
+        pinned = GraphicalJoin(cat, q, plan=plan)
+        assert pinned.plan() is plan
+    spans = tracer.find("plan:stats")
+    assert len(spans) == 2
+    for sp, gj, branch in zip(spans, (searched, pinned),
+                              ("searched", "pinned")):
+        chain, anc = [], sp
+        while anc.parent_id is not None:
+            anc = _parent(tracer, anc)
+            chain.append(anc.name)
+        # the search opens the pass inside its own span
+        assert chain == (["plan:search"] if branch == "searched" else []) \
+            + ["phase:plan", branch]
+        factors = gj._executor.logical.stats.factors
+        assert sp.args == {"rows": base, "entries": sum(
+            f.num_entries for f in factors)}
+    # the searched plan kept its degree vectors, the pinned one built none
+    assert searched._executor.logical.stats.factor_stats
+    assert pinned._executor.logical.stats.factor_stats == []
+
+
+def test_filter_records_its_mask_and_each_levels_propagation():
+    cat, q = _lastfm()
+    gfjs = GraphicalJoin(cat, q).run()
+    frame = SummaryFrame.of(gfjs)
+    cut = int(np.median(gfjs.domains["U1"].values))
+    want = frame.filter(U1=lambda v: v < cut).sum("A2")
+
+    tracer = Tracer()
+    with tracer.span("request"):
+        assert frame.filter() is frame          # no predicate, no work
+        assert frame.filter(U1=lambda v: v < cut).sum("A2") == want
+    assert len(tracer.find("algebra:filter")) == 2
+    (mask,) = tracer.find("filter:mask")
+    assert _parent(tracer, mask).name == "algebra:filter"
+    assert mask.args == {"runs": gfjs.levels[-1].num_runs}
+    props = tracer.find("filter:propagate")
+    assert [sp.args for sp in props] == [
+        {"level": j, "runs": gfjs.levels[j].num_runs}
+        for j in range(len(gfjs.levels) - 1)]
+    assert all(_parent(tracer, sp).span_id == mask.parent_id
+               for sp in props)
+    # each level's segment sum waits on the device inside its own span
+    waits = [_parent(tracer, sp) for sp in tracer.find("segsum:wait")]
+    assert [sp.span_id for sp in props] == [
+        w.span_id for w in waits if w.name == "filter:propagate"]
