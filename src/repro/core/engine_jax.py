@@ -132,9 +132,45 @@ def maybe_dense_message(
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "acc_dtype"))
 def _segsum_padded(seg, x, w, *, num_segments: int, acc_dtype):
-    """Fused multiply + segment-sum on bucket-padded inputs (DESIGN.md §2)."""
+    """Fused multiply + segment-sum on bucket-padded inputs (DESIGN.md §2).
+
+    Integer accumulators rely on ``seg`` being sorted: segment ``s`` covers
+    ``[end[s-1], end[s])``, so its total is a difference of the inclusive
+    prefix sum at its last entry and at the previous segment's, with the
+    ends found by a binary search of the ids — no scatter.  The int64
+    prefix may wrap, but differences modulo 2**64 stay exact whenever the
+    segment's true total fits in int64.  ``seg=None`` means one segment: a
+    plain reduction.  Float accumulators keep the scatter
+    (``jax.ops.segment_sum``): a float prefix difference cancels.
+    """
     prod = x.astype(acc_dtype) * w.astype(acc_dtype)
-    return jax.ops.segment_sum(prod, seg, num_segments=num_segments)
+    if jnp.issubdtype(acc_dtype, jnp.floating):
+        return jax.ops.segment_sum(prod, seg, num_segments=num_segments)
+    if seg is None:
+        return jnp.sum(prod, keepdims=True)
+    ends = jnp.searchsorted(seg, jnp.arange(num_segments, dtype=seg.dtype),
+                            side="right")
+    zero = jnp.zeros(1, acc_dtype)
+    at_end = jnp.concatenate([zero, _blocked_cumsum(prod)])[ends]
+    return jnp.diff(at_end, prepend=zero)
+
+
+_SCAN_BLOCK = 512   # ops.next_bucket's floor: every bucket is a multiple
+
+
+def _blocked_cumsum(x):
+    """Inclusive prefix sum of a bucket-padded vector, 512 entries a row.
+
+    Same sums as ``jnp.cumsum`` (modulo 2**64 for int64), but XLA's TPU
+    compile time of a flat int64 cumsum grows with its length (about 40 s
+    at 2**21), and a row-wise one stays near 2 s up to 2**26.
+    """
+    n = x.shape[0]
+    if n <= _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    rows = jnp.cumsum(x.reshape(-1, _SCAN_BLOCK), axis=1)
+    totals = rows[:, -1]
+    return (rows + (_blocked_cumsum(totals) - totals)[:, None]).reshape(n)
 
 
 def _f32_exact_conclusive(values: np.ndarray, weights: np.ndarray, n: int,
@@ -173,8 +209,9 @@ def segment_weighted_sum(
     inputs whose total magnitude fits f32-exact range ride the Pallas
     ``mul_segsum`` kernel (MXU one-hot matmul per tile); everything else —
     including all CPU traffic, where the kernel would only run interpreted —
-    takes a jit'd XLA segment-sum with bucketized padding (int64 exact for
-    integers, f64 for floats), so the jit cache stays O(log^2 max-size).
+    takes the jit'd ``_segsum_padded`` with bucketized padding, so the jit
+    cache stays O(log^2 max-size): integers as an exact int64 sorted prefix
+    sum (a plain reduction for one segment), floats as an f64 scatter.
 
     ``bound``: optional caller-known upper bound on sum|values*weights|,
     letting the kernel pick skip its O(n) exactness scan (see
@@ -194,18 +231,23 @@ def segment_weighted_sum(
                              interpret=interpret)
         return _segsum_wait(out).astype(INT)
     # exact path: pad entries + segment count to power-of-two buckets;
-    # padding rows land in a dead trailing segment that gets sliced off
+    # padding rows (value 0) land in a dead trailing segment that gets
+    # sliced off
     acc = jnp.float64 if floaty else jnp.int64
-    s_pad = ops.next_bucket(num_segments + 1)
     n_pad = ops.next_bucket(n)
-    seg_p = np.full(n_pad, s_pad - 1, np.int32)
-    seg_p[:n] = seg_ids
     x_p = np.zeros(n_pad, values.dtype)
     x_p[:n] = values
     w_p = np.zeros(n_pad, weights.dtype)
     w_p[:n] = weights
-    out = _segsum_padded(jnp.asarray(seg_p), jnp.asarray(x_p),
-                         jnp.asarray(w_p), num_segments=s_pad, acc_dtype=acc)
+    if num_segments == 1 and not floaty:
+        seg_d, s_pad = None, 1
+    else:
+        s_pad = ops.next_bucket(num_segments + 1)
+        seg_p = np.full(n_pad, s_pad - 1, np.int32)
+        seg_p[:n] = seg_ids
+        seg_d = jnp.asarray(seg_p)
+    out = _segsum_padded(seg_d, jnp.asarray(x_p), jnp.asarray(w_p),
+                         num_segments=s_pad, acc_dtype=acc)
     res = _segsum_wait(out)[:num_segments]
     return res if floaty else res.astype(INT)
 

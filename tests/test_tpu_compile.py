@@ -94,3 +94,16 @@ def test_sorted_runs_compiles(one_chip):
     _compile(engine_jax._sorted_runs,
              _spec(one_chip, (1 << 12,), jnp.int64))
 
+
+@pytest.mark.parametrize("segments", [1 << 9, None])
+def test_segsum_padded_integer_body_compiles(one_chip, segments):
+    # 2**19 entries: the prefix sum's row totals (1,024) are scanned in rows
+    # too; a flat int64 cumsum of this length took about 40 s to compile
+    n = 1 << 19
+    seg = None if segments is None else _spec(one_chip, (n,), jnp.int32)
+    compiled = _compile(
+        lambda s, x, w: engine_jax._segsum_padded(
+            s, x, w, num_segments=segments or 1, acc_dtype=jnp.int64),
+        seg, _spec(one_chip, (n,), jnp.int64),
+        _spec(one_chip, (n,), jnp.int64))
+    assert "scatter" not in compiled.as_text()
